@@ -311,12 +311,13 @@ def jpq_topk_bench(fast: bool = True):
         # track the unsharded permuted sweep (docs/serving.md)
         from repro import dist
         from repro.core import sharded
+        from repro.launch.mesh import make_mesh
         shards = 8
         if N % shards or jax.device_count() < shards:
             # a caller-preset XLA_FLAGS can pin fewer host devices;
             # skip the mesh rows rather than abort the whole bench
             continue
-        mesh = jax.make_mesh((1, shards), ("data", "model"))
+        mesh = make_mesh((1, shards), ("data", "model"))
         local_n = N // shards
         bn_m = tops.mesh_prune_block_n(
             N, shards, target=min(8192, max(128, local_n // 8)))

@@ -520,7 +520,6 @@ def rerank_candidates(values, ids, k: int):
     total order (±0.0 included), so re-ranking masked candidates equals
     a top-k over the masked materialised scores — the SeqRecModel serve
     protocol's final step."""
-    from repro.kernels.jpq_topk.jpq_topk import desc_sort_key
-    _, ids2, vv = jax.lax.sort((desc_sort_key(values), ids, values),
-                               num_keys=2)
+    from repro.kernels.jpq_topk.jpq_topk import sort_total_order
+    vv, ids2 = sort_total_order(values, ids)
     return vv[..., :k], ids2[..., :k]

@@ -70,10 +70,17 @@ def logits(p, h, *, use_kernel: bool = False):
     part = partial_scores(p, h)                             # [..., m, b]
     codes = p["codes"].value.astype(jnp.int32)              # [N, m]
     m = codes.shape[1]
-    s = part[..., 0, :][..., codes[:, 0]]
-    for j in range(1, m):
-        s = s + part[..., j, :][..., codes[:, j]]
-    return s                                               # [..., N] fp32
+
+    def pick(j):                                           # [..., N]
+        pj = jax.lax.dynamic_index_in_dim(part, j, part.ndim - 2, False)
+        return pj[..., jax.lax.dynamic_index_in_dim(codes, j, 1, False)]
+
+    # splits are added one at a time in a loop, in split order: unrolled,
+    # XLA keeps all m gathered [..., N] buffers live for one fused sum
+    # (m x the logits' bytes: 14 GB of a v5e's 16 at batch 64, L 200,
+    # N 34,744), where the loop holds two
+    return jax.lax.fori_loop(1, m, lambda j, s: s + pick(j),
+                             pick(0))                      # [..., N] fp32
 
 
 def reconstruct_table(p):

@@ -13,10 +13,10 @@ from __future__ import annotations
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec
 
 from repro.dist import rules as _rules
-from repro.dist.compat import shard_map
 
 
 def pooled_lookup(table, ids, weights):

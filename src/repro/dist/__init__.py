@@ -35,8 +35,7 @@ Public API
 
 Submodules: ``rules`` (the table + resolver), ``compression``
 (data-parallel gradient exchange with bf16/int8 error feedback),
-``hlo`` (collective-traffic accounting for the dry-run roofline),
-``compat`` (jax version bridges).
+and ``hlo`` (collective-traffic accounting for the dry-run roofline).
 """
 from __future__ import annotations
 
@@ -45,11 +44,8 @@ import math
 import jax
 from jax.sharding import NamedSharding, PartitionSpec
 
-from repro.dist import compat as _compat
 from repro.dist.rules import (DATA_AXES, DEFAULT_RULES, _CTX,  # noqa: F401
                               resolve_axes, use_mesh_rules)
-
-_compat.install_cost_analysis_shim()
 
 __all__ = ["resolve_axes", "use_mesh_rules", "constrain",
            "data_shard_count", "params_shardings", "DEFAULT_RULES"]
@@ -66,7 +62,7 @@ def constrain(x, axes):
     # compressed-gradient dp step traces model losses there); the
     # enclosing shard_map's specs already pin the layout, so the
     # advisory constraint simply stands down
-    manual = _compat.manual_axis_names()
+    manual = jax.sharding.get_abstract_mesh().manual_axes
     if manual and any(a in manual for a in mesh.shape):
         return x
     spec = resolve_axes(axes, x.shape, mesh, _CTX.rules)

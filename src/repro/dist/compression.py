@@ -133,10 +133,10 @@ import math
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec
 
 from repro.dist import rules as _rules
-from repro.dist.compat import shard_map
 
 METHODS = ("none", "bf16", "int8")
 

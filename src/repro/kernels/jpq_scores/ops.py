@@ -33,7 +33,7 @@ def jpq_scores(h, centroids, codes, *, block_b: int = 256,
     bn = min(block_n, _ceil_mult(N, 128))
     Bp, Np = _ceil_mult(B, bb), _ceil_mult(N, bn)
     partial = jnp.pad(partial, ((0, Bp - B), (0, 0), (0, 0)))
-    codes_p = jnp.pad(codes, ((0, Np - N), (0, 0)))   # stays int8 in HBM
+    codes_p = jnp.pad(codes, ((0, Np - N), (0, 0)))
     out = jpq_scores_lut(partial, codes_p, block_b=bb, block_n=bn,
                          interpret=interpret)
     return out[:B, :N].reshape(*lead, N)

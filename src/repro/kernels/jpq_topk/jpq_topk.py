@@ -9,13 +9,15 @@ partial-score LUT ``P [B, m, b]`` and the codebook ``codes [N, m]`` in
 ``[block_n]``-sized item tiles and keeps only a running ``(values,
 ids)`` top-k per query, so the ``[B, N]`` tensor never exists in HBM.
 
-Per tile (same MXU formulation as jpq_scores): the ``[Nt]`` codes tile
-becomes ``m`` one-hot matrices contracted against the LUT, giving the
-tile scores ``S [Bt, Nt]`` in registers/VMEM; padding columns (N not a
-multiple of block_n) are masked to −inf against the *global* item id;
-then the running list is merged by one ``top_k`` over the concatenated
-``[Bt, k + Nt]`` candidates.  One-hot picks are exact (x·1 + Σ 0), so
-fused scores are bit-identical to the gather reference.  Signed zeros:
+Per tile (same MXU formulation and operand layout as jpq_scores,
+``tile_scores``): the ``[Nt]`` codes tile becomes ``m`` one-hot
+matrices contracted against the LUT, giving the tile scores
+``S [Bt, Nt]`` in registers/VMEM; padding columns (N not a multiple of
+block_n) are masked to −inf against the *global* item position; then
+the tile is folded into the running list (``merge_tile`` below).
+One-hot picks are exact (three bf16 parts of the LUT, one exact
+product each), so fused scores are bit-identical to the gather
+reference.  Signed zeros:
 the public entrypoints (``ops.jpq_topk`` / ``ops.jpq_topk_lut``)
 canonicalise ``-0.0 → +0.0`` in the LUT before it reaches any backend
 — the one-hot MXU dot flattens ``-0.0`` to ``+0.0`` (−0.0 + 0.0 =
@@ -28,15 +30,26 @@ numerically unchanged: −0.0 == +0.0).
 
 Grid: ``(B/Bt, N/Nt)`` with the item dim innermost and *sequential*
 ("arbitrary" semantics): the output blocks are revisited at every item
-step — ``index_map (i, n) -> (i, 0)`` — so the running top-k lives in
+step — ``index_map (i, n) -> (i, 0)`` — so the running list lives in
 VMEM across the whole item sweep and is initialised under
 ``pl.when(n == 0)``.
 
-Tie-breaking is stable on item id: ``lax.top_k`` prefers the lowest
-input index, the running list sits *before* the tile in the merge
-concat, and item tiles are swept in ascending-id order — so equal
+Merge (Mosaic has no in-kernel ``top_k``, sort or gather): the running
+list is an UNORDERED set of the k best (value, id) pairs seen so far
+under the total order "value desc, then id asc".  Per tile, a
+``while_loop`` extracts the tile's best remaining candidate per row
+(max value, lowest id among equal values) and, where it beats the
+set's worst entry (min value, highest id among equal values), writes
+it over that entry; the loop stops when no row's best candidate gets
+in — every later candidate of the tile ranks below it.  The trip count
+is one more than the most candidates any row admits from the tile:
+up to k+1 on the first tile, a handful once the list has warmed.  The
+wrapper orders the final set with one small ``lax.sort`` on the same
+total order.  That order does not depend on sweep order, so equal
 scores resolve to the smallest item id, exactly like a top-k over the
-materialised matrix.
+materialised matrix — for the ascending sweep and for a permuted one.
+Empty slots hold (−inf, 0), which no padding column (−inf, id ≥ 0)
+can displace.
 
 Dynamic pruning (the PQTopK follow-up, "Efficient Recommendation with
 Millions of Items by Dynamic Pruning of Sub-Item Embeddings"):
@@ -57,12 +70,9 @@ resumed across phases (the cross-shard threshold exchange splits one
 sweep into two kernel launches).
 
 VMEM per step (Bt=256, Nt=512, m=8, b=256, k=128):
-  P tile   256·8·256·4 = 2.0 MiB     one-hot 256·512·4 = 0.5 MiB
-  merge    256·(512+128)·4·2 ≈ 1.3 MiB   running 2·256·128·4 = 0.25 MiB
--> ~4 MiB << 16 MiB.  Portability note: the merge uses
-``lax.top_k`` + ``take_along_axis`` on the lane dim; on Mosaic
-versions without a gather lowering, swap the id recovery for a one-hot
-contraction.  Interpret mode (the test oracle) is exact either way.
+  P tile   8·256·256·4 = 2.0 MiB     one-hot 256·512·2 = 0.25 MiB
+  tile scores 256·512·4 = 0.5 MiB    running 2·256·128·4 = 0.25 MiB
+-> ~3.5 MiB << 16 MiB.
 """
 from __future__ import annotations
 
@@ -72,6 +82,8 @@ import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
+
+from repro.kernels.jpq_scores.jpq_scores import kernel_operands, tile_scores
 
 
 def desc_sort_key(v):
@@ -132,12 +144,55 @@ def topk_total_order(cat_v, cat_i, k: int):
     return vv[:, :k], ii[:, :k]
 
 
-def _kernel(p_ref, codes_ref, vals_ref, ids_ref, *, m: int, b: int,
-            k: int, block_n: int, n_items: int):
-    # p_ref:     [Bt, m, b]  fp32 LUT tile (same block for every n step)
-    # codes_ref: [Nt, m]     int32 codes tile
-    # vals_ref:  [Bt, k]     running top-k values  (revisited across n)
-    # ids_ref:   [Bt, k]     running top-k item ids
+def sort_total_order(v, i):
+    """Order (values, ids) rows by value desc, then id asc — the total
+    order ``lax.top_k`` induces on the materialised matrix."""
+    _, ii, vv = jax.lax.sort((desc_sort_key(v), i, v), num_keys=2)
+    return vv, ii
+
+
+_ID_MAX = 2 ** 31 - 1
+
+
+def _any(mask):
+    return jnp.max(mask.astype(jnp.int32)) > 0
+
+
+def merge_tile(s, tile_ids, vals, ids):
+    """Fold one tile into the running unordered top-k set (module
+    docstring, "Merge").  s [Bt, Nt] fp32 tile scores, tile_ids
+    [1 or Bt, Nt] int32 item ids, vals / ids [Bt, k] the set."""
+    k = vals.shape[1]
+    tile_ids = jnp.broadcast_to(tile_ids, s.shape)
+    slot = jax.lax.broadcasted_iota(jnp.int32, vals.shape, 1)
+
+    def body(carry):
+        s, vals, ids, _ = carry
+        bv = jnp.max(s, axis=1, keepdims=True)
+        bi = jnp.min(jnp.where(s == bv, tile_ids, _ID_MAX), axis=1,
+                     keepdims=True)
+        wv = jnp.min(vals, axis=1, keepdims=True)
+        wi = jnp.max(jnp.where(vals == wv, ids, -1), axis=1, keepdims=True)
+        enter = (bv > wv) | ((bv == wv) & (bi < wi))
+        ws = jnp.min(jnp.where((vals == wv) & (ids == wi), slot, k),
+                     axis=1, keepdims=True)
+        put = enter & (slot == ws)
+        vals = jnp.where(put, bv, vals)
+        ids = jnp.where(put, bi, ids)
+        s = jnp.where(enter & (tile_ids == bi), -jnp.inf, s)
+        return s, vals, ids, _any(enter)
+
+    _, vals, ids, _ = jax.lax.while_loop(
+        lambda c: c[3], body, (s, vals, ids, jnp.bool_(True)))
+    return vals, ids
+
+
+def _kernel(p_ref, codes_ref, vals_ref, ids_ref, *, block_n: int,
+            n_items: int):
+    # p_ref:     [m, Bt, b]  fp32 LUT tile (same block for every n step)
+    # codes_ref: [m, Nt]     int32 codes tile
+    # vals_ref:  [Bt, k]     running top-k set, values (revisited across n)
+    # ids_ref:   [Bt, k]     running top-k set, item ids
     n = pl.program_id(1)
 
     @pl.when(n == 0)
@@ -145,41 +200,31 @@ def _kernel(p_ref, codes_ref, vals_ref, ids_ref, *, m: int, b: int,
         vals_ref[...] = jnp.full(vals_ref.shape, -jnp.inf, jnp.float32)
         ids_ref[...] = jnp.zeros(ids_ref.shape, jnp.int32)
 
-    centroid_ids = jax.lax.broadcasted_iota(jnp.int32, (b, block_n), 0)
-    acc = jnp.zeros((p_ref.shape[0], block_n), jnp.float32)
-    for j in range(m):                      # static unroll over code splits
-        cj = codes_ref[:, j].astype(jnp.int32)
-        onehot = (cj[None, :] == centroid_ids).astype(jnp.float32)
-        acc += jnp.dot(p_ref[:, j, :], onehot,
-                       preferred_element_type=jnp.float32)
-
+    acc = tile_scores(p_ref, codes_ref)
     item_ids = n * block_n + jax.lax.broadcasted_iota(
-        jnp.int32, acc.shape, 1)
+        jnp.int32, (1, block_n), 1)
     acc = jnp.where(item_ids < n_items, acc, -jnp.inf)  # N-padding mask
-
-    cat_v = jnp.concatenate([vals_ref[...], acc], axis=1)
-    cat_i = jnp.concatenate([ids_ref[...], item_ids], axis=1)
-    v, pos = jax.lax.top_k(cat_v, k)
-    vals_ref[...] = v
-    ids_ref[...] = jnp.take_along_axis(cat_i, pos, axis=1)
+    vals_ref[...], ids_ref[...] = merge_tile(acc, item_ids, vals_ref[...],
+                                             ids_ref[...])
 
 
 def _kernel_pruned(p_ref, codes_ref, ids_ref, pres_ref, floor_ref, iv_ref,
-                   ii_ref, vals_ref, ids_out_ref, skip_ref, *, m: int,
-                   b: int, k: int, block_n: int, n_items: int,
-                   n_batch: int, tie_break_ids: bool):
-    # p_ref:    [Bt, m, b]   fp32 LUT tile (same block for every n step)
-    # codes_ref:[Nt, m]      int32 codes tile, in sweep order
-    # ids_ref:  [Nt, 1]      int32 ORIGINAL item id of each sweep row
+                   ii_ref, vals_ref, ids_out_ref, skip_ref, *,
+                   block_n: int, n_items: int, n_batch: int,
+                   tie_break_ids: bool):
+    # p_ref:    [m, Bt, b]   fp32 LUT tile (same block for every n step)
+    # codes_ref:[m, Nt]      int32 codes tile, in sweep order
+    # ids_ref:  [1, Nt]      int32 ORIGINAL item id of each sweep row
     # pres_ref: [1, m, b]    fp32 0/1 — code c occurs in this tile, split j
     # floor_ref:[Bt, 1]      fp32 per-query candidate floor (-inf = none;
     #                        padded batch rows carry +inf so they never
     #                        demand a tile the real rows would skip)
-    # iv_ref/ii_ref: [Bt, k] running-list seed written at n == 0 (-inf/0
+    # iv_ref/ii_ref: [Bt, k] running-set seed written at n == 0 (-inf/0
     #                        for a cold sweep; the previous phase's lists
     #                        when resuming across a threshold exchange)
-    # vals_ref / ids_out_ref: [Bt, k] running top-k (revisited across n)
-    # skip_ref: [1, 1]       int32 1 iff this (i, n) tile was skipped
+    # vals_ref / ids_out_ref: [Bt, k] running top-k set (revisited across n)
+    # skip_ref: [1, N/Nt]    int32 SMEM row of this batch block's skip
+    #                        flags: 1 iff tile n was skipped
     i = pl.program_id(0)
     n = pl.program_id(1)
 
@@ -192,16 +237,15 @@ def _kernel_pruned(p_ref, codes_ref, ids_ref, pres_ref, floor_ref, iv_ref,
     # Any item in the tile scores <= ub (its codes are all present), so
     # when ub cannot beat the running k-th value for ANY query row the
     # whole gather+accumulate+merge is provably a no-op and is skipped.
-    bt = p_ref.shape[0]
-    ub = jnp.zeros((bt,), jnp.float32)
+    m, bt, _ = p_ref.shape
+    ub = jnp.zeros((bt, 1), jnp.float32)
     for j in range(m):
-        pj = jnp.where(pres_ref[0, j, :][None, :] > 0, p_ref[:, j, :],
-                       -jnp.inf)
-        ub = ub + jnp.max(pj, axis=1)
+        pj = jnp.where(pres_ref[0, j:j + 1, :] > 0, p_ref[j], -jnp.inf)
+        ub = ub + jnp.max(pj, axis=1, keepdims=True)
     # padded batch rows must never demand a tile
-    row = i * bt + jax.lax.broadcasted_iota(jnp.int32, (bt,), 0)
+    row = i * bt + jax.lax.broadcasted_iota(jnp.int32, (bt, 1), 0)
     ub = jnp.where(row < n_batch, ub, -jnp.inf)
-    theta = vals_ref[:, k - 1]
+    theta = jnp.min(vals_ref[...], axis=1, keepdims=True)   # k-th value
     # identity sweep: an equal score loses the id tie-break to every
     # running entry (all from earlier tiles = smaller ids), so strict >
     # is required to enter.  Under a permutation ties break on original
@@ -211,38 +255,19 @@ def _kernel_pruned(p_ref, codes_ref, ids_ref, pres_ref, floor_ref, iv_ref,
     # the final k-th value and win on id), and combines per ROW before
     # the any-reduce: a row whose bound clears its own θ but not the
     # floor must not demand the tile for everyone else.
-    need = jnp.any(ok & (ub >= floor_ref[:, 0]))
-    skip_ref[0, 0] = jnp.where(need, 0, 1).astype(jnp.int32)
+    need = _any(ok & (ub >= floor_ref[...]))
+    skip_ref[0, n] = jnp.where(need, 0, 1).astype(jnp.int32)
 
     @pl.when(need)
     def _body():
-        centroid_ids = jax.lax.broadcasted_iota(jnp.int32, (b, block_n), 0)
-        acc = jnp.zeros((bt, block_n), jnp.float32)
-        for j in range(m):                  # static unroll over code splits
-            cj = codes_ref[:, j].astype(jnp.int32)
-            onehot = (cj[None, :] == centroid_ids).astype(jnp.float32)
-            acc += jnp.dot(p_ref[:, j, :], onehot,
-                           preferred_element_type=jnp.float32)
+        acc = tile_scores(p_ref, codes_ref)
         # N-padding mask is by sweep POSITION (ids are original ids and
         # arbitrary under a permutation, positions are not)
-        pos = n * block_n + jax.lax.broadcasted_iota(jnp.int32, acc.shape, 1)
+        pos = n * block_n + jax.lax.broadcasted_iota(
+            jnp.int32, (1, block_n), 1)
         acc = jnp.where(pos < n_items, acc, -jnp.inf)
-        item_ids = jnp.broadcast_to(
-            ids_ref[:, 0].astype(jnp.int32)[None, :], acc.shape)
-        cat_v = jnp.concatenate([vals_ref[...], acc], axis=1)
-        cat_i = jnp.concatenate([ids_out_ref[...], item_ids], axis=1)
-        if tie_break_ids:
-            # (value, id) total order — sweep-order independent, ==
-            # lax.top_k over the materialised matrix.  Portability
-            # note: the int top_k / small variadic sort inside may need
-            # a Mosaic-version check; interpret mode is exact.
-            v, ii = topk_total_order(cat_v, cat_i, k)
-            vals_ref[...] = v
-            ids_out_ref[...] = ii
-        else:
-            v, pos_k = jax.lax.top_k(cat_v, k)
-            vals_ref[...] = v
-            ids_out_ref[...] = jnp.take_along_axis(cat_i, pos_k, axis=1)
+        vals_ref[...], ids_out_ref[...] = merge_tile(
+            acc, ids_ref[...], vals_ref[...], ids_out_ref[...])
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_items", "n_batch",
@@ -255,7 +280,7 @@ def jpq_topk_tiles_pruned(partial, codes, ids, present, floor, init_vals,
                           interpret: bool = False):
     """Score-bound dynamically-pruned variant of ``jpq_topk_tiles``.
 
-    Extra inputs: ``ids [N, 1]`` original item id per sweep row (iota
+    Extra inputs: ``ids [N]`` original item id per sweep row (iota
     when unpermuted), ``present [N/block_n, m, b]`` 0/1 presence of each
     code in each tile (built from the UNPADDED codes; padding rows
     contribute nothing, which only loosens nothing — they are masked by
@@ -278,15 +303,15 @@ def jpq_topk_tiles_pruned(partial, codes, ids, present, floor, init_vals,
     assert present.shape == (grid[1], m, b), (present.shape, grid)
     assert floor.shape == (B, 1) and init_vals.shape == (B, k), \
         (floor.shape, init_vals.shape)
-    return pl.pallas_call(
-        functools.partial(_kernel_pruned, m=m, b=b, k=k, block_n=block_n,
+    v, i, skips = pl.pallas_call(
+        functools.partial(_kernel_pruned, block_n=block_n,
                           n_items=n_items, n_batch=n_batch,
                           tie_break_ids=tie_break_ids),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, m, b), lambda i, n: (i, 0, 0)),
-            pl.BlockSpec((block_n, m), lambda i, n: (n, 0)),
-            pl.BlockSpec((block_n, 1), lambda i, n: (n, 0)),
+            pl.BlockSpec((m, block_b, b), lambda i, n: (0, i, 0)),
+            pl.BlockSpec((m, block_n), lambda i, n: (0, n)),
+            pl.BlockSpec((1, block_n), lambda i, n: (0, n)),
             pl.BlockSpec((1, m, b), lambda i, n: (n, 0, 0)),
             pl.BlockSpec((block_b, 1), lambda i, n: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i, n: (i, 0)),
@@ -295,21 +320,22 @@ def jpq_topk_tiles_pruned(partial, codes, ids, present, floor, init_vals,
         out_specs=(
             pl.BlockSpec((block_b, k), lambda i, n: (i, 0)),
             pl.BlockSpec((block_b, k), lambda i, n: (i, 0)),
-            pl.BlockSpec((1, 1), lambda i, n: (i, n)),
+            pl.BlockSpec((1, grid[1]), lambda i, n: (i, 0),
+                         memory_space=pltpu.SMEM),
         ),
         out_shape=(
             jax.ShapeDtypeStruct((B, k), jnp.float32),
             jax.ShapeDtypeStruct((B, k), jnp.int32),
             jax.ShapeDtypeStruct(grid, jnp.int32),
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="jpq_topk_pruned",
-    )(partial.astype(jnp.float32), codes.astype(jnp.int32),
-      ids.astype(jnp.int32), present.astype(jnp.float32),
-      floor.astype(jnp.float32), init_vals.astype(jnp.float32),
-      init_ids.astype(jnp.int32))
+    )(*kernel_operands(partial, codes), ids.astype(jnp.int32)[None, :],
+      present.astype(jnp.float32), floor.astype(jnp.float32),
+      init_vals.astype(jnp.float32), init_ids.astype(jnp.int32))
+    return (*sort_total_order(v, i), skips)
 
 
 @functools.partial(jax.jit, static_argnames=("k", "n_items", "block_b",
@@ -317,7 +343,7 @@ def jpq_topk_tiles_pruned(partial, codes, ids, present, floor, init_vals,
 def jpq_topk_tiles(partial, codes, *, k: int, n_items: int,
                    block_b: int = 256, block_n: int = 512,
                    interpret: bool = False):
-    """partial [B, m, b] fp32, codes [N, m] int32 (N padded to block_n,
+    """partial [B, m, b] fp32, codes [N, m] int (N padded to block_n,
     B padded to block_b by the caller) -> (values [B, k] fp32,
     ids [B, k] int32), top-k over the first ``n_items`` columns.
     Requires 0 < k <= n_items <= N."""
@@ -326,13 +352,12 @@ def jpq_topk_tiles(partial, codes, *, k: int, n_items: int,
     assert B % block_b == 0 and N % block_n == 0, (B, N, block_b, block_n)
     assert 0 < k <= n_items <= N, (k, n_items, N)
     grid = (B // block_b, N // block_n)
-    return pl.pallas_call(
-        functools.partial(_kernel, m=m, b=b, k=k, block_n=block_n,
-                          n_items=n_items),
+    v, i = pl.pallas_call(
+        functools.partial(_kernel, block_n=block_n, n_items=n_items),
         grid=grid,
         in_specs=[
-            pl.BlockSpec((block_b, m, b), lambda i, n: (i, 0, 0)),
-            pl.BlockSpec((block_n, m), lambda i, n: (n, 0)),
+            pl.BlockSpec((m, block_b, b), lambda i, n: (0, i, 0)),
+            pl.BlockSpec((m, block_n), lambda i, n: (0, n)),
         ],
         out_specs=(
             pl.BlockSpec((block_b, k), lambda i, n: (i, 0)),
@@ -342,8 +367,9 @@ def jpq_topk_tiles(partial, codes, *, k: int, n_items: int,
             jax.ShapeDtypeStruct((B, k), jnp.float32),
             jax.ShapeDtypeStruct((B, k), jnp.int32),
         ),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
         name="jpq_topk",
-    )(partial.astype(jnp.float32), codes.astype(jnp.int32))
+    )(*kernel_operands(partial, codes))
+    return sort_total_order(v, i)

@@ -266,7 +266,7 @@ def pruned_sweep(partial, st: PruneState, k: int, *, block_n: int,
     Bp, Np = _ceil_mult(B, bb), _ceil_mult(N, block_n)
     partial_p = jnp.pad(partial, ((0, Bp - B), (0, 0), (0, 0)))
     codes_p = jnp.pad(st.codes, ((0, Np - N), (0, 0)))
-    ids_p = jnp.pad(st.ids, (0, Np - N))[:, None]
+    ids_p = jnp.pad(st.ids, (0, Np - N))
     floor_p = jnp.pad(floor[:, None], ((0, Bp - B), (0, 0)),
                       constant_values=jnp.inf)
     iv_p = jnp.pad(carry[0], ((0, Bp - B), (0, 0)),
@@ -284,6 +284,8 @@ def pruned_sweep(partial, st: PruneState, k: int, *, block_n: int,
 
 _SCAN_BLOCK_N = 131072
 _PRUNE_BLOCK_N = 8192
+_KERNEL_BLOCK_N = 512       # VMEM-sized tile of the Mosaic kernel
+_LANES = 128                # Mosaic tiles a block's lane dim in 128s
 
 
 def scan_block_n(N: int, target: int = _SCAN_BLOCK_N) -> int:
@@ -294,32 +296,49 @@ def scan_block_n(N: int, target: int = _SCAN_BLOCK_N) -> int:
     return _ceil_mult(-(-N // nb), 128)
 
 
-def prune_block_n(N: int, target: int = _PRUNE_BLOCK_N) -> int:
-    """Pruned-scan tile size.  Bounds need granularity to bite: at the
-    unpruned ~128k tile every one of the b codes occurs in every tile,
-    the presence mask saturates, and no tile can ever be skipped — so
-    pruned sweeps default to ~8k tiles (still >> merge cost)."""
+def prune_block_n(N: int, target: int | None = None) -> int:
+    """Tile size of a pruning state.  On TPU it is the kernel's own
+    tile (512), which the pruned kernel sweeps.  The scan backend
+    wants ~8k tiles: bounds need granularity to bite (at the unpruned
+    ~128k tile every one of the b codes occurs in every tile, the
+    presence mask saturates and no tile can ever be skipped), and 8k
+    keeps the per-tile merge cheap relative to the gather."""
+    if target is None:
+        if _on_tpu():
+            return min(_KERNEL_BLOCK_N, _ceil_mult(N, _LANES))
+        target = _PRUNE_BLOCK_N
     return scan_block_n(N, target)
 
 
 def mesh_prune_block_n(N: int, shards: int,
-                       target: int = _PRUNE_BLOCK_N) -> int:
+                       target: int | None = None) -> int:
     """Pruned tile size for a ``shards``-way row-sharded catalogue: the
-    divisor of the per-shard row count closest to ``target``, so one
-    GLOBAL permute-then-shard PruneState tiles every shard's rows
-    exactly (``core.sharded.fused_topk_over_codes`` refuses states
-    whose tiles straddle shard boundaries — rebuilding per request is
-    the O(N·m) bug this replaces)."""
+    divisor of the per-shard row count closest to ``target`` (the
+    platform's ``prune_block_n`` tile by default; on TPU only multiples
+    of 128, which the kernel's blocks need), so one GLOBAL
+    permute-then-shard PruneState tiles every shard's rows exactly
+    (``core.sharded.fused_topk_over_codes`` refuses states whose tiles
+    straddle shard boundaries — rebuilding per request is the O(N·m)
+    bug this replaces)."""
     assert N % shards == 0, (N, shards)
     local_n = N // shards
-    best = local_n
+    tpu = _on_tpu()
+    if target is None:
+        target = _KERNEL_BLOCK_N if tpu else _PRUNE_BLOCK_N
+    step = _LANES if tpu else 1
+    best = local_n if local_n % step == 0 else None
     d = 1
     while d * d <= local_n:
         if local_n % d == 0:
             for c in (d, local_n // d):
-                if abs(c - target) < abs(best - target):
+                if c % step == 0 and (best is None or
+                                      abs(c - target) < abs(best - target)):
                     best = c
         d += 1
+    if best is None:
+        raise ValueError(
+            f"no tile of a multiple of {step} rows divides the "
+            f"{local_n}-row shards of a {shards}-way mesh (N={N})")
     return best
 
 

@@ -6,6 +6,7 @@ touch jax device state (the dry-run sets XLA_FLAGS before first init).
 from __future__ import annotations
 
 import jax
+from jax.sharding import AxisType
 
 # v5e hardware constants used by the roofline analysis
 PEAK_FLOPS_BF16 = 197e12        # per chip
@@ -13,10 +14,21 @@ HBM_BW = 819e9                  # bytes/s per chip
 ICI_BW = 50e9                   # bytes/s per link
 
 
+def make_mesh(shape, axes, *, devices=None):
+    """``jax.make_mesh`` with Auto axes.  The model, exchange and
+    serving code shard by logical-axis rules and sharding constraints
+    (``repro.dist``), i.e. the compiler propagates layouts; jax's
+    default Explicit axes would instead type every eager slice and
+    gather of a sharded array, which that code does not annotate."""
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
+    return make_mesh(shape, axes)
 
 
 def make_host_mesh(n_devices: int = 1, model: int = 1):
@@ -27,4 +39,4 @@ def make_host_mesh(n_devices: int = 1, model: int = 1):
             f"model axis {model} must divide the device count "
             f"{n_devices}")
     data = n_devices // model
-    return jax.make_mesh((data, model), ("data", "model"))
+    return make_mesh((data, model), ("data", "model"))

@@ -131,6 +131,8 @@ def main():
 
     import contextlib
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     from repro import dist

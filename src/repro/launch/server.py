@@ -69,6 +69,8 @@ def main():
 
     import contextlib
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import numpy as np
 
     from repro import dist

@@ -78,8 +78,12 @@ def main():
         args.devices = args.mesh
     if args.devices > 1:
         os.environ["XLA_FLAGS"] = (
-            f"--xla_force_host_platform_device_count={args.devices}")
+            os.environ.get("XLA_FLAGS", "")
+            + f" --xla_force_host_platform_device_count={args.devices}"
+        ).strip()
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     import jax
     import jax.numpy as jnp
     from repro.configs import list_archs, get_bundle

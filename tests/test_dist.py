@@ -33,7 +33,8 @@ class TestRules:
         body = """
         import jax, json
         from repro.dist.rules import resolve_axes
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         # heads=40 not divisible by model=4? 40%4==0 -> shards
         s1 = resolve_axes(("embed", "heads", "head_dim"), (64, 40, 16), mesh)
         # heads=6 not divisible by 4 -> falls back to replicated
@@ -51,7 +52,8 @@ class TestRules:
         body = """
         import jax, json
         from repro.dist.rules import resolve_axes
-        mesh = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 2, 2), ("pod", "data", "model"))
         s = resolve_axes(("batch", "seq"), (8, 16), mesh)
         print(str(s))
         """
@@ -78,14 +80,15 @@ class TestShardedTraining:
             data = SyntheticSequences(SeqDataConfig(n_users=64, n_items=40,
                                                     seq_len=8))
             tr = Trainer(model, OptConfig(lr=1e-2, kind="sgd"),
-                         TrainConfig(steps=4, batch_size=8, log_every=1,
+                         TrainConfig(steps=8, batch_size=8, log_every=1,
                                      eval_every=0),
                          data_fn=lambda s: data.train_batch(s, 8),
                          mesh=mesh)
             _, hist = tr.run()
             return [h["loss"] for h in hist if "loss" in h]
 
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         l_mesh = losses(mesh)
         l_one = losses(None)
         print(json.dumps([l_mesh, l_one]))
@@ -106,7 +109,8 @@ class TestShardedTraining:
         p = jpq.init(KeyGen(0), 4096, 32, 4, 16)
         h = jax.random.normal(jax.random.PRNGKey(1), (8, 32))
         ref = jpq.logits(nn.with_values(p, nn.values(p)), h)
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         codes_sh = jax.device_put(p["codes"].value,
                                   NamedSharding(mesh, P("model", None)))
         p2 = {"codes": nn.P(codes_sh, p["codes"].axes),
@@ -127,7 +131,8 @@ class TestGradCompression:
         from repro.dist.compression import (make_dp_grad_fn,
                                             zeros_error_state,
                                             payload_bytes)
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         target = jnp.asarray(np.random.default_rng(0)
                              .standard_normal(16), jnp.float32)
 
@@ -179,7 +184,8 @@ class TestGradCompression:
         for method in ("none", "bf16", "int8"):
             per_mesh = []
             for d in (8, 4, 2):
-                mesh = jax.make_mesh((d,), ("data",))
+                from repro.launch.mesh import make_mesh
+                mesh = make_mesh((d,), ("data",))
                 gf = make_dp_grad_fn(loss_fn, mesh, method=method,
                                      accum_shards=8)
                 values = {"w": jnp.zeros(16)}
@@ -210,7 +216,8 @@ class TestGradCompression:
         import jax, jax.numpy as jnp, numpy as np
         from repro.dist.compression import (make_dp_grad_fn,
                                             zeros_error_state)
-        mesh = jax.make_mesh((4,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4,), ("data",))
         values = {"w": jnp.ones(8),
                   "codes": jnp.arange(6, dtype=jnp.uint8)}
 
@@ -234,7 +241,8 @@ class TestGradCompression:
         body = """
         import jax
         from repro.dist.compression import make_dp_grad_fn
-        mesh = jax.make_mesh((8,), ("data",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("data",))
         try:
             make_dp_grad_fn(lambda v, b: 0.0, mesh, accum_shards=12)
             print("NO-RAISE")
@@ -255,13 +263,15 @@ class TestElasticRestore:
 
         t = {"w": jnp.arange(64.0).reshape(8, 8),
              "m": jnp.ones((8, 8))}
-        mesh_a = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh_a = make_mesh((4, 2), ("data", "model"))
         sh_a = {"w": NamedSharding(mesh_a, P("data", "model")),
                 "m": NamedSharding(mesh_a, P("data", None))}
         t_a = jax.tree.map(jax.device_put, t, sh_a)
         with tempfile.TemporaryDirectory() as d:
             save_checkpoint(d, t_a, 5)
-            mesh_b = jax.make_mesh((2, 2), ("data", "model"))
+            from repro.launch.mesh import make_mesh
+            mesh_b = make_mesh((2, 2), ("data", "model"))
             sh_b = {"w": NamedSharding(mesh_b, P("data", "model")),
                     "m": NamedSharding(mesh_b, P(None, "model"))}
             restored, step = restore_checkpoint(d, t, shardings=sh_b)
@@ -298,7 +308,8 @@ class TestDryrunMachinery:
         from repro import dist
         bundle = get_bundle("fm")
         cell = bundle.cells["serve_p99"]
-        mesh = jax.make_mesh((4, 2), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((4, 2), ("data", "model"))
         model = bundle.make_model("serve_p99")
         fn, args, donate = dr.build_cell_args(bundle, cell, model, mesh)
         with dist.use_mesh_rules(mesh):

@@ -138,7 +138,8 @@ class TestTopkOverItems:
     def test_matches_under_mesh_context(self):
         """One-device mesh exercises the shard_map path (shards=1)."""
         scores = jax.random.normal(jax.random.PRNGKey(1), (2, 33))
-        mesh = jax.make_mesh((1,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1,), ("model",))
         with use_mesh_rules(mesh):
             v, i = sharded.topk_over_items(scores, 3)
         rv, ri = jax.lax.top_k(scores, 3)

@@ -142,7 +142,8 @@ class TestGoldenParity:
         h = jax.random.normal(jax.random.PRNGKey(1), (B, D))
         rv, ri = jax.lax.top_k(emb.logits(p, h), K)
         perm = np.arange(N)[::-1].copy()
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with dist.use_mesh_rules(mesh):
             state = engine.build_prune_state(p["codes"].value, emb.cfg.b,
                                              shards=4, perm=perm)

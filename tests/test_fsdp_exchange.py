@@ -64,7 +64,8 @@ class TestFsdpLayoutUnits:
 
     def test_partition_specs_tree(self):
         from jax.sharding import PartitionSpec
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         vals = {"w": jnp.zeros((16, 4)), "b": jnp.zeros((3,)),
                 "codes": jnp.zeros((16,), jnp.int32)}
         specs = compression.fsdp_partition_specs(vals, mesh, 8)
@@ -98,7 +99,8 @@ class TestFsdpLayoutUnits:
         assert compression.payload_bytes(vals, "int8") == n * 1
 
     def test_fsdp_shardings_roundtrip_single_device(self):
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         vals = {"w": jnp.arange(64, dtype=jnp.float32).reshape(16, 4),
                 "b": jnp.arange(3, dtype=jnp.float32)}
         shs = compression.fsdp_shardings(vals, mesh, 8)

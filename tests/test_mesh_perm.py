@@ -79,7 +79,8 @@ class TestMeshPermConformance:
         rv, ri = jpq_topk_lut_ref(canon, codes, k)
         perm = jnp.argsort(rank).astype(jnp.int32)  # sweep: popular 1st
         state = tops.prepare_pruning(codes, b, bn, perm=perm)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         res = {}
         def ex(v, i):
             return bool(np.array_equal(np.asarray(v), np.asarray(rv))
@@ -152,7 +153,8 @@ class TestMeshPermConformance:
         vr, ir = jax.jit(lambda p, b: model.retrieve(
             p, b, top_k=7, fused=False))(p, batch)
         warm = serve_mod.ThresholdState(0.8)
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         ok = True
         with dist.use_mesh_rules(mesh):
             f = jax.jit(lambda p, b, w: model.retrieve(

@@ -93,7 +93,8 @@ class TestRetrieveTopk:
         p = model.init_params(rng)
         vr, ir = jax.jit(lambda p, b: model.retrieve(p, b, top_k=7,
                                                      fused=False))(p, batch)
-        mesh = jax.make_mesh((8,), ("model",))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((8,), ("model",))
         with dist.use_mesh_rules(mesh):
             vf, if_ = jax.jit(lambda p, b: model.retrieve(p, b,
                                                           top_k=7))(p, batch)
@@ -136,7 +137,8 @@ class TestRetrieveTopk:
         codes = jax.random.randint(jax.random.fold_in(key, 2), (512, 4),
                                    0, 16, jnp.int32)
         rv, ri = jpq_topk_lut_ref(part, codes, 9)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with dist.use_mesh_rules(mesh):
             v, i = jax.jit(lambda pp, cc: sharded.fused_topk_over_codes(
                 pp, cc, 9, prune=True))(part, codes)
@@ -161,7 +163,8 @@ class TestRetrieveTopk:
         codes = jax.random.randint(jax.random.fold_in(key, 2), (512, 4),
                                    0, 16, jnp.int32)
         rv, ri = jpq_topk_lut_ref(part, codes, 9)
-        mesh = jax.make_mesh((2, 4), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((2, 4), ("data", "model"))
         with dist.use_mesh_rules(mesh):
             v, i = jax.jit(lambda pp, cc:
                            sharded.fused_topk_over_codes(pp, cc, 9))(

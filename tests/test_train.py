@@ -447,7 +447,8 @@ class TestTrainerIntegration:
         import jax as _jax
         cfg = SeqRecConfig(arch="gru4rec", n_items=30, max_len=8,
                            d_model=16, n_layers=1)
-        mesh = _jax.make_mesh((1, 1), ("data", "model"))
+        from repro.launch.mesh import make_mesh
+        mesh = make_mesh((1, 1), ("data", "model"))
         with pytest.raises(ValueError, match="microbatches"):
             Trainer(SeqRecModel(cfg), OptConfig(),
                     TrainConfig(grad_compression="bf16", microbatches=2),
