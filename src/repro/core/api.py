@@ -9,6 +9,7 @@ from __future__ import annotations
 import dataclasses
 from typing import Optional
 
+import jax
 import jax.numpy as jnp
 import numpy as np
 
@@ -67,11 +68,12 @@ class Embedding:
 
     def logits(self, p, h):
         c = self.cfg
-        if c.kind == "full":
-            return _full.logits(p, h)
-        if c.kind == "jpq":
-            return _jpq.logits(p, h, use_kernel=c.use_kernel)
-        return _qr.logits(p, h, c.n_items)
+        with jax.named_scope("item_emb.logits"):
+            if c.kind == "full":
+                return _full.logits(p, h)
+            if c.kind == "jpq":
+                return _jpq.logits(p, h, use_kernel=c.use_kernel)
+            return _qr.logits(p, h, c.n_items)
 
     def bag_lookup(self, p, ids, segment_ids, num_segments: int,
                    *, combiner: str = "sum", weights=None):
@@ -82,7 +84,6 @@ class Embedding:
         is gather + segment_sum per the taxonomy, with a fused Pallas
         path for the full-table kind (repro/kernels/embedding_bag).
         """
-        import jax
         emb = self.lookup(p, ids)                       # [nnz, d]
         if weights is not None:
             emb = emb * weights[:, None].astype(emb.dtype)

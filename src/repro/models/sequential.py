@@ -127,33 +127,35 @@ class SeqRecModel:
     # --------------------------------------------------------- encoder
     def encode(self, p, seq, *, rng=None):
         """seq int[B, S] (0 = pad) -> hidden [B, S, d]."""
-        cfg = self.cfg
-        kg = KeyGen(rng) if rng is not None else None
-        x = self.emb.lookup(p["item_emb"], seq)
-        x = jnp.where((seq > 0)[..., None], x, 0.0)
-        pad_mask = seq > 0
-        if cfg.arch in ("sasrec", "bert4rec"):
-            S = seq.shape[1]
-            x = x * jnp.sqrt(cfg.d_model).astype(x.dtype)
-            x = x + p["pos_emb"].value[:S][None]
-            if kg:
-                x = _dropout(kg(), x, cfg.dropout)
-            for blk in p["blocks"]:
-                h = attention(blk["attn"], self.attn_cfg,
-                              L.layernorm(blk["ln1"], x), pad_mask=pad_mask)
+        with jax.named_scope("seqrec.encode"):
+            cfg = self.cfg
+            kg = KeyGen(rng) if rng is not None else None
+            x = self.emb.lookup(p["item_emb"], seq)
+            x = jnp.where((seq > 0)[..., None], x, 0.0)
+            pad_mask = seq > 0
+            if cfg.arch in ("sasrec", "bert4rec"):
+                S = seq.shape[1]
+                x = x * jnp.sqrt(cfg.d_model).astype(x.dtype)
+                x = x + p["pos_emb"].value[:S][None]
                 if kg:
-                    h = _dropout(kg(), h, cfg.dropout)
-                x = x + h
-                h = L.dense_mlp(blk["mlp"], L.layernorm(blk["ln2"], x))
-                if kg:
-                    h = _dropout(kg(), h, cfg.dropout)
-                x = x + h
-            x = L.layernorm(p["ln_f"], x)
-        else:                                           # gru4rec
-            for gp in p["gru"]:
-                x, _ = gru_scan(gp, x)
-            x = L.linear(p["proj"], x)
-        return x
+                    x = _dropout(kg(), x, cfg.dropout)
+                for blk in p["blocks"]:
+                    h = attention(blk["attn"], self.attn_cfg,
+                                  L.layernorm(blk["ln1"], x),
+                                  pad_mask=pad_mask)
+                    if kg:
+                        h = _dropout(kg(), h, cfg.dropout)
+                    x = x + h
+                    h = L.dense_mlp(blk["mlp"], L.layernorm(blk["ln2"], x))
+                    if kg:
+                        h = _dropout(kg(), h, cfg.dropout)
+                    x = x + h
+                x = L.layernorm(p["ln_f"], x)
+            else:                                       # gru4rec
+                for gp in p["gru"]:
+                    x, _ = gru_scan(gp, x)
+                x = L.linear(p["proj"], x)
+            return x
 
     # ------------------------------------------------------------ loss
     def train_loss(self, p, batch, rng=None):
@@ -165,9 +167,7 @@ class SeqRecModel:
         valid = labels > 0
         if cfg.loss == "full_ce":
             logits = self.emb.logits(p["item_emb"], h)  # [B,S,R]
-            logits = self._mask_special(logits)
-            ce = _xent(logits, labels)
-            loss = jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
+            loss = self._softmax_loss(logits, labels)
         elif cfg.loss == "code_ce":                     # semantic head
             loss = self._code_loss(p, h, labels, valid)
         else:                                           # sampled_bce
@@ -194,9 +194,8 @@ class SeqRecModel:
         if self.cfg.loss == "code_ce":                  # semantic head
             loss = self._code_loss(p, h, targets, valid)
             return loss, {"loss": loss}
-        logits = self._mask_special(self.emb.logits(p["item_emb"], h))
-        ce = _xent(logits, targets)
-        loss = jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
+        loss = self._softmax_loss(self.emb.logits(p["item_emb"], h),
+                                  targets)
         if self.cfg.semantic_weight > 0.0:
             aux = self._code_loss(p, h, targets, valid)
             loss = loss + self.cfg.semantic_weight * aux
@@ -212,6 +211,14 @@ class SeqRecModel:
         from repro.core import semantic as _semantic
         ce = _semantic.code_xent(p["item_emb"], h, targets)   # [B, S]
         return jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
+
+    def _softmax_loss(self, logits, targets):
+        """Full-softmax cross-entropy of ``targets`` (0 = no target),
+        averaged over the positions that have one."""
+        with jax.named_scope("seqrec.loss_head"):
+            ce = _xent(self._mask_special(logits), targets)
+            valid = targets > 0
+            return jnp.sum(ce * valid) / jnp.maximum(jnp.sum(valid), 1)
 
     def _mask_special(self, logits):
         """Never rank pad / [MASK] rows."""

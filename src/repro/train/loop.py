@@ -313,50 +313,75 @@ class Trainer:
         # the restore path above is the consumer
         ckpt_meta = {"train_spec": self.spec.layout_stamp(self.mesh)}
 
+        # a step's time runs from the previous step's completion (or
+        # its own start, if the host was busy past that) to its own
+        # completion.  Dispatch returns before the device is done, so
+        # each step is waited for one step behind, with the next one
+        # already queued, and before anything that reads its state.
+        in_flight = None                   # (step, mets, start)
+        t_done = time.perf_counter()
+
+        def settle():
+            nonlocal in_flight, t_done
+            if in_flight is None:
+                return
+            s, mets, t_start = in_flight
+            in_flight = None
+            jax.block_until_ready(mets)
+            now = time.perf_counter()
+            dt, t_done = now - max(t_done, t_start), now
+            self._watchdog(s, dt)
+            if s % cfg.log_every == 0 or s == cfg.steps - 1:
+                self.history.append({
+                    "step": s, **{k: float(v) for k, v in mets.items()},
+                    **payload_mets, "sec": dt})
+
         with ctx:
             for step in range(start_step, cfg.steps):
                 t0 = time.perf_counter()
-                batch = jax.tree.map(jnp.asarray, self.data_fn(step))
-                srng = jax.random.fold_in(rng, step)
-                if self._use_dp:
-                    values, opt_state, err_state, mets = train_step(
-                        values, opt_state, err_state, batch, srng)
-                else:
-                    values, opt_state, mets = train_step(
-                        values, opt_state, batch, srng)
-                done_step = step + 1
-                dt = time.perf_counter() - t0
-                self._watchdog(step, dt)
-                if step % cfg.log_every == 0 or step == cfg.steps - 1:
-                    mets = {k: float(v) for k, v in mets.items()}
-                    self.history.append({"step": step, **mets,
-                                         **payload_mets, "sec": dt})
-                if ckpt and cfg.ckpt_every and \
-                        (step + 1) % cfg.ckpt_every == 0:
-                    ckpt.save(_ckpt_state(), step + 1,
-                              metadata=ckpt_meta)
-                    last_saved = step + 1
-                if self._preempted:
-                    if ckpt and last_saved != step + 1:
+                with jax.profiler.StepTraceAnnotation("train.step",
+                                                      step_num=step):
+                    batch = jax.tree.map(jnp.asarray, self.data_fn(step))
+                    srng = jax.random.fold_in(rng, step)
+                    if self._use_dp:
+                        values, opt_state, err_state, mets = train_step(
+                            values, opt_state, err_state, batch, srng)
+                    else:
+                        values, opt_state, mets = train_step(
+                            values, opt_state, batch, srng)
+                    settle()
+                    in_flight = (step, mets, t0)
+                    done_step = step + 1
+                    if ckpt and cfg.ckpt_every and \
+                            (step + 1) % cfg.ckpt_every == 0:
+                        settle()
                         ckpt.save(_ckpt_state(), step + 1,
                                   metadata=ckpt_meta)
-                        ckpt.wait()
                         last_saved = step + 1
-                    break
-                if self.eval_fn and cfg.eval_every and \
-                        (step + 1) % cfg.eval_every == 0:
-                    params = nn.with_values(params_meta, values)
-                    ev = self.eval_fn(params)
-                    self.history.append({"step": step, **{
-                        f"eval_{k}": float(v) for k, v in ev.items()}})
-                    metric = float(next(iter(ev.values())))
-                    if cfg.early_stop_patience:
-                        if metric > best_metric + 1e-6:
-                            best_metric, stale = metric, 0
-                        else:
-                            stale += 1
-                            if stale >= cfg.early_stop_patience:
-                                break
+                    if self._preempted:
+                        settle()
+                        if ckpt and last_saved != step + 1:
+                            ckpt.save(_ckpt_state(), step + 1,
+                                      metadata=ckpt_meta)
+                            ckpt.wait()
+                            last_saved = step + 1
+                        break
+                    if self.eval_fn and cfg.eval_every and \
+                            (step + 1) % cfg.eval_every == 0:
+                        settle()
+                        params = nn.with_values(params_meta, values)
+                        ev = self.eval_fn(params)
+                        self.history.append({"step": step, **{
+                            f"eval_{k}": float(v) for k, v in ev.items()}})
+                        metric = float(next(iter(ev.values())))
+                        if cfg.early_stop_patience:
+                            if metric > best_metric + 1e-6:
+                                best_metric, stale = metric, 0
+                            else:
+                                stale += 1
+                                if stale >= cfg.early_stop_patience:
+                                    break
+            settle()
         if ckpt:
             if last_saved != done_step:
                 ckpt.save(_ckpt_state(), done_step,

@@ -94,44 +94,45 @@ def apply_updates(cfg: OptConfig, state, values, grads, *,
     and adam silently ignored it, so a sweep cell setting
     ``kind="sgd", weight_decay=0.1`` trained undecayed.
     """
-    step = state["step"] + 1
-    lr = schedule_lr(cfg, step)
-    gn = global_norm(grads) if grad_norm is None else grad_norm
-    scale = jnp.ones(())
-    if cfg.clip_norm is not None:
-        scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gn, 1e-9))
+    with jax.named_scope("train.optimizer"):
+        step = state["step"] + 1
+        lr = schedule_lr(cfg, step)
+        gn = global_norm(grads) if grad_norm is None else grad_norm
+        scale = jnp.ones(())
+        if cfg.clip_norm is not None:
+            scale = jnp.minimum(1.0, cfg.clip_norm / jnp.maximum(gn, 1e-9))
 
-    b1, b2, eps = cfg.b1, cfg.b2, cfg.eps
-    t = step.astype(jnp.float32)
-    bc1 = 1.0 - b1 ** t
-    bc2 = 1.0 - b2 ** t
+        b1, b2, eps = cfg.b1, cfg.b2, cfg.eps
+        t = step.astype(jnp.float32)
+        bc1 = 1.0 - b1 ** t
+        bc2 = 1.0 - b2 ** t
 
-    def _upd(p, g, m, v):
-        if not _is_float(p):
-            return p, m, v
-        g = g.astype(jnp.float32) * scale
-        p32 = p.astype(jnp.float32)
-        if cfg.kind == "sgd":
-            update = g
-        else:
-            m = b1 * m + (1.0 - b1) * g
-            v = b2 * v + (1.0 - b2) * jnp.square(g)
-            update = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
-        if cfg.weight_decay > 0:
-            update = update + cfg.weight_decay * p32
-        new_p = p32 - lr * update
-        return new_p.astype(p.dtype), m, v
+        def _upd(p, g, m, v):
+            if not _is_float(p):
+                return p, m, v
+            g = g.astype(jnp.float32) * scale
+            p32 = p.astype(jnp.float32)
+            if cfg.kind == "sgd":
+                update = g
+            else:
+                m = b1 * m + (1.0 - b1) * g
+                v = b2 * v + (1.0 - b2) * jnp.square(g)
+                update = (m / bc1) / (jnp.sqrt(v / bc2) + eps)
+            if cfg.weight_decay > 0:
+                update = update + cfg.weight_decay * p32
+            new_p = p32 - lr * update
+            return new_p.astype(p.dtype), m, v
 
-    flat_p, treedef = jax.tree.flatten(values)
-    flat_g = treedef.flatten_up_to(grads)
-    flat_m = treedef.flatten_up_to(state["m"])
-    flat_v = treedef.flatten_up_to(state["v"])
-    out = [_upd(p, g, m, v) for p, g, m, v in
-           zip(flat_p, flat_g, flat_m, flat_v)]
-    new_values = treedef.unflatten([o[0] for o in out])
-    new_state = {
-        "m": treedef.unflatten([o[1] for o in out]),
-        "v": treedef.unflatten([o[2] for o in out]),
-        "step": step,
-    }
+        flat_p, treedef = jax.tree.flatten(values)
+        flat_g = treedef.flatten_up_to(grads)
+        flat_m = treedef.flatten_up_to(state["m"])
+        flat_v = treedef.flatten_up_to(state["v"])
+        out = [_upd(p, g, m, v) for p, g, m, v in
+               zip(flat_p, flat_g, flat_m, flat_v)]
+        new_values = treedef.unflatten([o[0] for o in out])
+        new_state = {
+            "m": treedef.unflatten([o[1] for o in out]),
+            "v": treedef.unflatten([o[2] for o in out]),
+            "step": step,
+        }
     return new_values, new_state, {"grad_norm": gn, "lr": lr}

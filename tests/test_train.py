@@ -333,6 +333,42 @@ class TestOptimizerWeightDecay:
             p0 - lr * (clipped_g + wd * p0), rel=1e-5)
 
 
+class TestStepScopes:
+    """The training step names its layers for a profile: each scope is
+    in the compiled step's op metadata, its gradient under
+    ``transpose(``."""
+
+    @pytest.mark.parametrize("arch, emb, scopes", [
+        ("sasrec", EmbeddingConfig(0, 0, kind="jpq", m=4, b=8),
+         ("seqrec.encode", "item_emb.logits", "seqrec.loss_head",
+          "train.optimizer")),
+        ("gru4rec", None, ("seqrec.encode",)),
+    ])
+    def test_compiled_step_carries_the_scopes(self, arch, emb, scopes):
+        import re
+
+        from repro.nn import module as nn
+        from repro.train.optimizer import init_opt_state
+        cfg = SeqRecConfig(arch=arch, n_items=30, max_len=8, d_model=16,
+                           n_layers=1, n_heads=2, d_ff=32, embedding=emb)
+        model = SeqRecModel(cfg)
+        tr = Trainer(model, OptConfig(), TrainConfig(batch_size=4),
+                     data_fn=None)
+        meta = model.init_params(jax.random.PRNGKey(0))
+        values = nn.values(meta)
+        seq = jnp.ones((4, 8), jnp.int32)
+        text = jax.jit(tr._build_step(meta)).lower(
+            values, init_opt_state(values), {"seq": seq, "labels": seq},
+            jax.random.PRNGKey(1)).compile().as_text()
+        names = re.findall(r'op_name="([^"]*)"', text)
+        for scope in scopes:
+            assert any(scope in n and "transpose(" not in n
+                       for n in names), scope
+        if "item_emb.logits" in scopes:
+            assert any("transpose(jvp(item_emb.logits))" in n
+                       for n in names)
+
+
 class TestTrainerIntegration:
     def test_preemption_saves_and_resumes(self):
         cfg = SeqRecConfig(arch="gru4rec", n_items=30, max_len=8,
@@ -534,6 +570,47 @@ class TestTrainerIntegration:
         assert len(tr._step_times) == 5
         tr.run()
         assert len(tr._step_times) == 5        # reset, not 10
+
+    def test_watchdog_times_the_device_work_not_the_enqueue(self):
+        """A step whose device work is slow, while its dispatch returns
+        at once, is flagged as a straggler: the loop times each step to
+        its completion, not to the return of its dispatch.  The slow
+        work is a loop of matrix products inside the jitted step, taken
+        only where the batch says so; JAX dispatches it asynchronously
+        (a host callback would run inline at dispatch instead)."""
+        cfg = SeqRecConfig(arch="gru4rec", n_items=30, max_len=8,
+                           d_model=16, n_layers=1)
+        data = SyntheticSequences(SeqDataConfig(n_users=40, n_items=30,
+                                                seq_len=8))
+        slow_step, steps = 25, 30
+
+        def heavy():
+            x = jnp.full((512, 512), 1e-3)
+            return jax.lax.fori_loop(
+                0, 300, lambda i, y: jnp.tanh(y @ y), x)[0, 0]
+
+        class SlowAt(SeqRecModel):
+            def train_loss(self, p, batch, rng=None):
+                loss, mets = super().train_loss(p, batch, rng)
+                work = jax.lax.cond(batch["slow"] > 0, heavy,
+                                    lambda: jnp.zeros(()))
+                return loss, {**mets, "slow_work": work}
+
+        def data_fn(s):
+            b = data.train_batch(s, 8)
+            b["slow"] = np.int32(s == slow_step)
+            return b
+
+        tr = Trainer(SlowAt(cfg), OptConfig(lr=1e-2),
+                     TrainConfig(steps=steps, batch_size=8, log_every=1,
+                                 eval_every=0),
+                     data_fn=data_fn)
+        _, hist = tr.run()
+        assert len(tr._step_times) == steps
+        assert slow_step in [h["step"] for h in hist
+                             if "straggler_sec" in h]
+        sec = {h["step"]: h["sec"] for h in hist if "sec" in h}
+        assert sorted(sec) == list(range(steps))
 
     def test_microbatch_grad_accumulation_matches(self):
         """2 microbatches ~= full batch (same data, mean loss)."""
