@@ -75,6 +75,16 @@ class Embedding:
                 return _jpq.logits(p, h, use_kernel=c.use_kernel)
             return _qr.logits(p, h, c.n_items)
 
+    def loss_logits(self, p, h):
+        """``logits`` as the full-softmax losses need them: every item
+        scored for many rows at once.  JPQ scores them as one product
+        with its reconstructed table (``jpq.table_logits``), which
+        agrees with ``logits`` to float32 rounding, not bitwise."""
+        if self.cfg.kind != "jpq":
+            return self.logits(p, h)
+        with jax.named_scope("item_emb.logits"):
+            return _jpq.table_logits(p, h)
+
     def bag_lookup(self, p, ids, segment_ids, num_segments: int,
                    *, combiner: str = "sum", weights=None):
         """EmbeddingBag: ragged multi-hot pooled lookup.

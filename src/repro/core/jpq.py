@@ -5,12 +5,21 @@ The embedding tensor ``[n_items, d]`` is replaced by
   centroids  float [m, b, d//m]   (trainable)
 Item i's embedding = concat_j centroids[j, codes[i, j]]  (paper Fig. 2).
 
-Two hot paths:
-  lookup(ids)  - input-side reconstruction (sequence of ids -> vectors)
-  logits(h)    - score *every* item for hidden state(s) h via the
-                 partial-score trick: P[j,c] = <h_j, centroids[j,c]>
-                 then scores_i = sum_j P[j, codes[i,j]].
-                 HBM traffic = m bytes/item instead of 4d bytes/item.
+Hot paths:
+  lookup(ids)      - input-side reconstruction (sequence of ids -> vectors)
+  logits(h)        - score *every* item for a few hidden states h via the
+                     partial-score trick: P[j,c] = <h_j, centroids[j,c]>
+                     then scores_i = sum_j P[j, codes[i,j]], in split order.
+                     Per query row it reads m code bytes per item instead
+                     of 4d table bytes; each of the m passes reads and
+                     writes the whole [..., N] scores.  Serving uses it
+                     (score_last, bind_engine and the engine's materialise
+                     scorer, whose PQTopK kernels match it bitwise).
+  table_logits(h)  - the same scores as one product h @ E.T with the table
+                     E [N, d] reconstructed once.  For the thousands of
+                     rows a full-softmax training step scores, it reads
+                     the table once and the MXU does the work; the
+                     softmax losses use it (Embedding.loss_logits).
 The Pallas TPU kernel for logits lives in repro/kernels/jpq_scores.
 """
 from __future__ import annotations
@@ -43,14 +52,18 @@ def init(kg: KeyGen, n_items: int, d: int, m: int, b: int = 256, *,
     }
 
 
+def _pick(cent, codes):
+    """codes int[..., m] -> concat_j cent[j, codes[..., j]]  [..., d]."""
+    m = cent.shape[0]
+    # gather per split: centroids[j, codes[..., j], :] -> [..., m, dk]
+    emb = cent[jnp.arange(m), codes.astype(jnp.int32)]
+    return emb.reshape(*codes.shape[:-1], -1)
+
+
 def lookup(p, ids):
     """ids int[...] -> embeddings [..., d]."""
-    cent = p["centroids"].value               # [m, b, dk]
-    m = cent.shape[0]
-    codes = jnp.take(p["codes"].value, ids, axis=0).astype(jnp.int32)
-    # gather per split: centroids[j, codes[..., j], :] -> [..., m, dk]
-    emb = cent[jnp.arange(m), codes]
-    return emb.reshape(*ids.shape, -1)
+    return _pick(p["centroids"].value,
+                 jnp.take(p["codes"].value, ids, axis=0))
 
 
 def partial_scores(p, h):
@@ -84,8 +97,20 @@ def logits(p, h, *, use_kernel: bool = False):
 
 
 def reconstruct_table(p):
-    """Materialise the full [n_items, d] table (tests / tiny catalogues)."""
-    return lookup(p, jnp.arange(p["codes"].shape[0]))
+    """Materialise the full [n_items, d] table (``table_logits``; 71 MB
+    at 34,744 items and d 512)."""
+    return _pick(p["centroids"].value, p["codes"].value)
+
+
+def table_logits(p, h):
+    """h [..., d] -> scores [..., n_items] as ``logits`` gives them, as
+    one product with the reconstructed table at the default precision
+    ``partial_scores`` runs at: the same products, summed over the
+    splits in another order.  Its gradient is two MXU products and the
+    pick's transpose into the centroids."""
+    with jax.named_scope("jpq.table_logits"):
+        tab = dist.constrain(reconstruct_table(p), ("items", "table_dim"))
+        return h.astype(jnp.float32) @ tab.T.astype(jnp.float32)
 
 
 def embedding_param_count(n_items: int, d: int, m: int, b: int = 256):

@@ -166,7 +166,7 @@ class SeqRecModel:
         h = self.encode(p, seq, rng=rng)
         valid = labels > 0
         if cfg.loss == "full_ce":
-            logits = self.emb.logits(p["item_emb"], h)  # [B,S,R]
+            logits = self.emb.loss_logits(p["item_emb"], h)  # [B,S,R]
             loss = self._softmax_loss(logits, labels)
         elif cfg.loss == "code_ce":                     # semantic head
             loss = self._code_loss(p, h, labels, valid)
@@ -194,7 +194,7 @@ class SeqRecModel:
         if self.cfg.loss == "code_ce":                  # semantic head
             loss = self._code_loss(p, h, targets, valid)
             return loss, {"loss": loss}
-        loss = self._softmax_loss(self.emb.logits(p["item_emb"], h),
+        loss = self._softmax_loss(self.emb.loss_logits(p["item_emb"], h),
                                   targets)
         if self.cfg.semantic_weight > 0.0:
             aux = self._code_loss(p, h, targets, valid)

@@ -72,6 +72,100 @@ class TestJPQ:
         assert g["codes"].dtype == jax.dtypes.float0
 
 
+def _table_case(n, d, m, b, repeated):
+    """A JPQ table and hidden states; ``repeated`` draws every code from
+    two centroid ids, so many items share each of them."""
+    codes = None
+    if repeated:
+        codes = np.random.default_rng(0).integers(0, 2, (n, m))
+    p = jpq.init(KeyGen(3), n, d, m, b, codes=codes)
+    h = jax.random.normal(jax.random.PRNGKey(4), (2, 3, d))
+    return p, h
+
+
+TABLE_CASES = [
+    pytest.param(300, 32, 4, 16, False, id="n300"),      # N % 128 != 0
+    pytest.param(257, 16, 8, 4, True, id="n257-repeated-ids"),
+    pytest.param(512, 64, 8, 256, False, id="n512-b256"),
+]
+
+
+class TestTableRoute:
+    """``table_logits`` scores the catalogue as one product with the
+    reconstructed table: the partial-score route's numbers to float32
+    rounding, and its gradients."""
+
+    @pytest.mark.parametrize("n, d, m, b, repeated", TABLE_CASES)
+    def test_scores_match_partial_route_and_table(self, n, d, m, b,
+                                                   repeated):
+        p, h = _table_case(n, d, m, b, repeated)
+        got = jpq.table_logits(p, h)
+        assert got.shape == (2, 3, n) and got.dtype == jnp.float32
+        np.testing.assert_allclose(np.asarray(got),
+                                   np.asarray(jpq.logits(p, h)),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(
+            np.asarray(got), np.asarray(h @ jpq.reconstruct_table(p).T),
+            rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("n, d, m, b, repeated", TABLE_CASES)
+    def test_gradients_match_partial_route(self, n, d, m, b, repeated):
+        from repro.nn import module as nn
+        p, h = _table_case(n, d, m, b, repeated)
+        w = jax.random.normal(jax.random.PRNGKey(5), (2, 3, n))
+
+        def grads(route):
+            def loss(v, hh):
+                s = route(nn.with_values(p, v), hh)
+                return jnp.sum(w * s) + jnp.sum(jax.nn.logsumexp(s, -1))
+            return jax.grad(loss, argnums=(0, 1), allow_int=True)(
+                nn.values(p), h)
+
+        (gv, gh), (rv, rh) = grads(jpq.table_logits), grads(jpq.logits)
+        np.testing.assert_allclose(np.asarray(gh), np.asarray(rh),
+                                   rtol=1e-5, atol=1e-5)
+        np.testing.assert_allclose(np.asarray(gv["centroids"]),
+                                   np.asarray(rv["centroids"]),
+                                   rtol=1e-5, atol=1e-5)
+        assert gv["codes"].dtype == jax.dtypes.float0
+
+    @pytest.mark.parametrize("arch", ["sasrec", "bert4rec"])
+    def test_full_ce_loss_takes_the_table_route(self, arch, monkeypatch):
+        """The full-softmax loss runs ``loss_logits``: the compiled step
+        names ``jpq.table_logits`` inside ``item_emb.logits`` both ways,
+        and the loss is the partial-score route's."""
+        import re
+
+        from repro.core.api import Embedding
+        from repro.models.sequential import SeqRecConfig, SeqRecModel
+        from repro.nn import module as nn
+        from repro.train.loop import TrainConfig, Trainer
+        from repro.train.optimizer import OptConfig, init_opt_state
+        cfg = SeqRecConfig(arch=arch, n_items=140, max_len=8, d_model=16,
+                           n_layers=1, n_heads=2, d_ff=32,
+                           embedding=EmbeddingConfig(0, 0, kind="jpq",
+                                                     m=4, b=8))
+        model = SeqRecModel(cfg)
+        meta = model.init_params(jax.random.PRNGKey(0))
+        values = nn.values(meta)
+        seq = jax.random.randint(jax.random.PRNGKey(1), (4, 8), 0, 141)
+        batch = {"seq": seq, "labels": seq, "targets": seq}
+        text = jax.jit(Trainer(model, OptConfig(), TrainConfig(batch_size=4),
+                               data_fn=None)._build_step(meta)).lower(
+            values, init_opt_state(values), batch,
+            jax.random.PRNGKey(2)).compile().as_text()
+        names = [n for n in re.findall(r'op_name="([^"]*)"', text)
+                 if "item_emb.logits" in n and "jpq.table_logits" in n]
+        assert any("transpose(" not in n for n in names)
+        assert any("transpose(jvp(item_emb.logits))" in n for n in names)
+
+        def loss():
+            return float(model.train_loss(meta, batch)[0])
+        got = loss()
+        monkeypatch.setattr(Embedding, "loss_logits", Embedding.logits)
+        assert got == pytest.approx(loss(), rel=1e-5)
+
+
 class TestAssignments:
     def _interactions(self, n_users=60, n_items=120, n=3000, seed=0):
         rng = np.random.default_rng(seed)

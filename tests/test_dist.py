@@ -97,26 +97,32 @@ class TestShardedTraining:
         np.testing.assert_allclose(l_mesh, l_one, rtol=1e-3)
         assert l_mesh[-1] < l_mesh[0]
 
-    def test_jpq_logits_shard_over_items(self):
+    @pytest.mark.parametrize("route", ["logits", "table_logits"])
+    def test_jpq_logits_shard_over_items(self, route):
         """Catalogue scoring with row-sharded codes compiles and matches
-        the single-device result (the retrieval_cand path)."""
-        body = """
+        the single-device result: the partial-score route (the
+        retrieval_cand path) and the table route (the softmax losses),
+        whose reconstructed table is constrained to the codes' rows."""
+        body = f"""
         import jax, jax.numpy as jnp, numpy as np
         from jax.sharding import NamedSharding, PartitionSpec as P
+        from repro import dist
         from repro.core import jpq
         from repro.nn.module import KeyGen
         from repro.nn import module as nn
+        route = jpq.{route}
         p = jpq.init(KeyGen(0), 4096, 32, 4, 16)
         h = jax.random.normal(jax.random.PRNGKey(1), (8, 32))
-        ref = jpq.logits(nn.with_values(p, nn.values(p)), h)
+        ref = route(nn.with_values(p, nn.values(p)), h)
         from repro.launch.mesh import make_mesh
         mesh = make_mesh((8,), ("model",))
         codes_sh = jax.device_put(p["codes"].value,
                                   NamedSharding(mesh, P("model", None)))
-        p2 = {"codes": nn.P(codes_sh, p["codes"].axes),
-              "centroids": p["centroids"]}
+        p2 = {{"codes": nn.P(codes_sh, p["codes"].axes),
+              "centroids": p["centroids"]}}
         with jax.sharding.use_mesh(mesh) if hasattr(jax.sharding, "use_mesh") else mesh:
-            out = jax.jit(lambda pp, hh: jpq.logits(pp, hh))(p2, h)
+            with dist.use_mesh_rules(mesh):
+                out = jax.jit(lambda pp, hh: route(pp, hh))(p2, h)
         np.testing.assert_allclose(np.asarray(out), np.asarray(ref),
                                    rtol=1e-4, atol=1e-4)
         print("OK")
